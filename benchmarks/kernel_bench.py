@@ -61,15 +61,15 @@ def main():
                  f"{bytes_dense / bytes_sparse:.1f}x"))
 
     # paged sparse decode on the serve layer's block-table layout: the
-    # same TopK computation as above, but on the [P,page,KV,D] physical
+    # same TopK computation as above, but on the [P,KV,page,D] physical
     # pool + per-request block tables the continuous-batching engine
     # actually produces (contiguous [B,S,KV,D] never exists there) — so
     # kernel numbers and serve_bench numbers are comparable
     from repro.models import sparse_attention
 
     r, nl, pp = 8, s // page, 1 + 8 * (s // page)
-    kpool = jnp.asarray(rng.normal(size=(pp, page, hkv, d)), jnp.bfloat16)
-    vpool = jnp.asarray(rng.normal(size=(pp, page, hkv, d)), jnp.bfloat16)
+    kpool = jnp.asarray(rng.normal(size=(pp, hkv, page, d)), jnp.bfloat16)
+    vpool = jnp.asarray(rng.normal(size=(pp, hkv, page, d)), jnp.bfloat16)
     spool = jnp.asarray(rng.normal(size=(pp, hkv, d)), jnp.float32)
     bt = np.stack([rng.choice(np.arange(1, pp), size=nl, replace=False)
                    for _ in range(r)])
@@ -84,9 +84,9 @@ def main():
                            q_, k_, v_, i_, ph_, po_, page))
     us_paged = timeit(paged_fn, qr, kpool, vpool, idx_bt, phys, pos)
     # structural run + parity of the Pallas paged kernel on this layout
-    from repro.kernels import paged_decode_attn
+    from repro.kernels import on_tpu, paged_decode_attn
     got = paged_decode_attn(phys, idx_bt, pos, qr, kpool, vpool,
-                            page_size=page, interpret=True)
+                            page_size=page, interpret=not on_tpu())
     want = paged_fn(qr, kpool, vpool, idx_bt, phys, pos)
     err = float(np.abs(np.asarray(got, np.float32)
                        - np.asarray(want, np.float32)).max())

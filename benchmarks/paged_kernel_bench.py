@@ -5,7 +5,7 @@ Two measurements on the same Poisson serve workload:
 
 1. **Step-loop speedup** — ``PagedEngine`` with pool-buffer donation and
    power-of-two row bucketing (the defaults) vs the pre-PR configuration
-   (no donation: every jitted call round-trips a full ``[L,P,page,KV,D]``
+   (no donation: every jitted call round-trips a full ``[L,P,KV,page,D]``
    pool copy; no bucketing: every ragged decode batch pads to
    ``max_batch``).  On this CPU container the win is dominated by the
    pool-copy and padded-row eliminations — the same levers, scaled up,

@@ -125,6 +125,8 @@ def _write_bench_json(name: str, headline: dict, us: float,
 
 
 def main(argv=None) -> int:
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     from . import paper_figs
     names = list(argv if argv is not None else sys.argv[1:]) \
         or list(paper_figs.ALL)

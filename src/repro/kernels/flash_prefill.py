@@ -72,8 +72,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, out_ref, acc_ref, m_ref, l_ref, *,
                                              "interpret"))
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = True, block_q: int = 128,
-                  block_k: int = 128, interpret: bool = True) -> jax.Array:
-    """q [B,S,H,D]; k, v [B,S,KV,D]; H = KV * G.  Returns [B,S,H,D]."""
+                  block_k: int = 128, interpret: bool) -> jax.Array:
+    """q [B,S,H,D]; k, v [B,S,KV,D]; H = KV * G.  Returns [B,S,H,D].
+    ``interpret`` runs the Pallas interpreter (``ops`` passes the
+    platform decision)."""
     b, s, h, d = q.shape
     _, sk, kv, _ = k.shape
     g = h // kv

@@ -23,7 +23,7 @@ def _gather_kernel(idx_ref, tbl_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def gather_rows(idx: jax.Array, table: jax.Array, *, block_d: int = 0,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """out[k, :] = table[idx[k], :].
 
     Args:
@@ -31,6 +31,8 @@ def gather_rows(idx: jax.Array, table: jax.Array, *, block_d: int = 0,
         the caller via ``repro.core.sparse.coalesce``).
       table: [N, D] source rows in HBM.
       block_d: tile width along D (0 = full row).
+      interpret: run the Pallas interpreter (``ops`` passes the
+        platform decision).
     """
     k_rows, = idx.shape
     n, d = table.shape

@@ -1,8 +1,11 @@
 """Public jit'd kernel API + the VMIG/LBD-style index preprocessing.
 
-``interpret`` defaults to True off-TPU (this container validates kernel
-bodies in interpret mode); on a TPU backend the same calls compile to
-Mosaic.  Every op has a pure-jnp oracle in ``ref.py``.
+The platform picks the mode: ``interpret`` defaults to True off-TPU
+(the CPU tests validate kernel bodies in the Pallas interpreter) and to
+False on a TPU backend, where the same calls compile to Mosaic.  The
+kernel modules themselves take ``interpret`` without a default, so no
+caller can run one interpreted on the chip by leaving it out.  Every op
+has a pure-jnp oracle in ``ref.py``.
 """
 
 from __future__ import annotations
@@ -11,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .flash_prefill import flash_prefill as _flash_prefill
 from .gather_rows import gather_rows as _gather_rows
 from .gather_spmm import gather_spmm as _gather_spmm
 from .moe_dispatch import moe_dispatch_matmul as _moe_dispatch_matmul
@@ -30,6 +34,12 @@ def _interp(interpret: bool | None) -> bool:
 
 def gather_rows(idx, table, *, interpret: bool | None = None):
     return _gather_rows(idx, table, interpret=_interp(interpret))
+
+
+def flash_prefill(q, k, v, *, causal: bool = True, block_q: int = 128,
+                  block_k: int = 128, interpret: bool | None = None):
+    return _flash_prefill(q, k, v, causal=causal, block_q=block_q,
+                          block_k=block_k, interpret=_interp(interpret))
 
 
 def gather_spmm(cols, vals, dense, *, block_n: int = 0,

@@ -3,7 +3,7 @@
 Where ``sparse_decode_attn`` runs on contiguous ``[B, S, KV, D]`` caches
 (the single-request layout), this kernel consumes the continuous-batching
 engine's *native* memory model directly: one layer of the physical page
-pool ``k/v_pool [P, page, KV, D]`` shared by every request, plus the
+pool ``k/v_pool [P, KV, page, D]`` shared by every request, plus the
 per-request TopK selection already resolved to **physical page ids**
 through the block table (``sparse_attention.select_pages_blocktable``).
 
@@ -29,8 +29,14 @@ masked), NULL-padded selection slots of short requests are fully masked,
 and fully-masked rows (padded batch slots) produce zeros, not NaNs.
 
 Layout: phys/idx int32 ``[R, KV, K]``; pos int32 ``[R]``;
-q ``[R, KV, G, D]``; k/v_pool ``[P, page, KV, D]`` (fp or int8 with the
-shared fixed-scale quant).  Output ``[R, KV, G, D]``.
+q ``[R, KV, G, D]``; k/v_pool ``[P, KV, page, D]`` (fp or int8 with the
+shared fixed-scale quant).  Output ``[R, KV, G, D]``.  The pool keeps
+the KV head outside the page so that one head's page is a contiguous
+``[page, D]`` slab: a K/V block is ``(1, 1, page, D)``, whose two minor
+dims are whole array dims — the tiling Mosaic accepts for any head
+count.  (Heads inside the page, ``[P, page, KV, D]``, would need a
+block of one head against the tiled KV dim, which the TPU compiler
+refuses.)
 """
 
 from __future__ import annotations
@@ -61,8 +67,8 @@ def _paged_kernel(phys_ref, idx_ref, pos_ref, q_ref, k_ref, v_ref, out_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                      # [G, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * kv_scale     # [page, D]
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * kv_scale
+    k = k_ref[0, 0].astype(jnp.float32) * kv_scale           # [page, D]
+    v = v_ref[0, 0].astype(jnp.float32) * kv_scale
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     # causal frontier mask in absolute token positions: the logical page
@@ -109,12 +115,12 @@ def _paged_decode_attn(phys, idx, pos, q, k_pool, v_pool, *, page_size: int,
             # indirect page DMA: the index map consults the prefetched
             # physical id — the pipeline prefetches page pi+1 while pi is
             # attended (double-buffered speculative gather, depth = K)
-            pl.BlockSpec((1, page_size, 1, d),
+            pl.BlockSpec((1, 1, page_size, d),
                          lambda ri, hi, pi, ph, ix, ps:
-                         (ph[ri, hi, pi], 0, hi, 0)),
-            pl.BlockSpec((1, page_size, 1, d),
+                         (ph[ri, hi, pi], hi, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, d),
                          lambda ri, hi, pi, ph, ix, ps:
-                         (ph[ri, hi, pi], 0, hi, 0)),
+                         (ph[ri, hi, pi], hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda ri, hi, pi, ph, ix, ps: (ri, hi, 0, 0)),
@@ -146,7 +152,7 @@ def paged_decode_attn(phys: jax.Array, idx: jax.Array, pos: jax.Array,
       idx:  int32 [R, KV, K] logical page ids (causal masking).
       pos:  int32 [R] per-request frontier positions.
       q:    [R, KV, G, D] one decode step's queries, GQA-grouped.
-      k_pool, v_pool: [P, page, KV, D] one layer of the physical pools
+      k_pool, v_pool: [P, KV, page, D] one layer of the physical pools
         (int8 pools dequant with the shared fixed scale).
       page_size: tokens per physical page.
       interpret: run the Pallas interpreter (defaults to True off-TPU).

@@ -59,7 +59,7 @@ def _attn_kernel(idx_ref, q_ref, k_ref, v_ref, out_ref,
                    static_argnames=("page_size", "interpret"))
 def sparse_decode_attn(idx: jax.Array, q: jax.Array, k: jax.Array,
                        v: jax.Array, *, page_size: int = 8,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """TopK-page decode attention.
 
     Args:
@@ -67,6 +67,8 @@ def sparse_decode_attn(idx: jax.Array, q: jax.Array, k: jax.Array,
       q:   [B, Hkv, G, D] query (one decode step, GQA-grouped).
       k,v: [B, S, Hkv, D] KV cache.
       page_size: tokens per gathered page (fuzzy-fetch granularity).
+      interpret: run the Pallas interpreter (``ops`` passes the
+        platform decision).
     Returns: [B, Hkv, G, D]
     """
     b, hkv, g, d = q.shape

@@ -9,12 +9,20 @@ device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with every axis ``Auto``: sharding follows the
+    arrays' placements and ``with_sharding_constraint`` hints, as the
+    model and serve code expect (jax's own default is ``Explicit``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_serve_mesh(tp: int = 1):
@@ -31,7 +39,7 @@ def make_serve_mesh(tp: int = 1):
             f"serve mesh wants tp={tp} but only {n} device(s) exist; on "
             "CPU set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{tp} before jax initialises")
-    return jax.make_mesh((tp,), ("model",))
+    return _auto_mesh((tp,), ("model",))
 
 
 def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
@@ -39,7 +47,7 @@ def make_host_mesh(data: int = 1, model: int = 1, pod: int = 0):
     and CPU examples."""
     shape = (pod, data, model) if pod else (data, model)
     axes = ("pod", "data", "model") if pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 # hardware constants (TPU v5e-class, per the assignment)

@@ -80,6 +80,7 @@ import numpy as np
 
 from ..configs import get_config
 from ..models import api
+from .compile_cache import setup_compile_cache
 from ..serve.engine import Engine, PagedEngine
 from ..serve.scheduler import PoissonArrivals
 
@@ -274,6 +275,7 @@ def _run_continuous(cfg, params, args):
 
 
 def main(argv=None):
+    setup_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--reduced", action="store_true")
@@ -380,7 +382,8 @@ def main(argv=None):
     p.add_argument("--capture", action="store_true",
                    help="record page traffic and replay through the "
                         "NVR simulator")
-    p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--seed", type=int, default=0,
+                   help="seed of the random weights and the workload")
     args = p.parse_args(argv)
     if args.tp > 1 and not args.continuous:
         p.error("--tp needs --continuous (only the paged engine shards)")
@@ -395,7 +398,7 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params = api.init_params(cfg, jax.random.PRNGKey(0))
+    params = api.init_params(cfg, jax.random.PRNGKey(args.seed))
     if args.continuous:
         return _run_continuous(cfg, params, args)
     return _run_single_batch(cfg, params, args)

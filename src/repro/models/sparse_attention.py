@@ -132,8 +132,6 @@ def sparse_decode_distributed(q, k, v, kpage, pos, *, page: int,
 
     q [B,KV,G,D]; k/v [B,S,KV,D]; kpage [B,NP,KV,D]; pos scalar.
     """
-    from jax.experimental.shard_map import shard_map
-
     ba = tuple(a for a in batch_axes if a in mesh.shape)
     sa = tuple(a for a in seq_axes if a in mesh.shape)
     ka = tuple(a for a in kv_axes if a in mesh.shape)
@@ -189,13 +187,13 @@ def sparse_decode_distributed(q, k, v, kpage, pos, *, page: int,
     bspec = ba if ba else None
     kspec = ka if ka else None
     sspec = sa if sa else None
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, kspec, None, None),
                   P(bspec, sspec, kspec, None),
                   P(bspec, sspec, kspec, None),
                   P(bspec, sspec, kspec, None), P()),
-        out_specs=P(bspec, kspec, None, None), check_rep=False)(
+        out_specs=P(bspec, kspec, None, None), check_vma=False)(
             q, k, v, kpage, pos)
 
 
@@ -250,8 +248,6 @@ def sparse_decode_distributed_full(q, k_full, v_full, kpage_li, li, pos, *,
                                    page: int, k_pages: int, mesh,
                                    batch_axes=(), seq_axes=(), kv_axes=()):
     """Distributed variant of ``sparse_decode_full`` (shard_map)."""
-    from jax.experimental.shard_map import shard_map
-
     ba = tuple(a for a in batch_axes if a in mesh.shape)
     sa = tuple(a for a in seq_axes if a in mesh.shape)
     ka = tuple(a for a in kv_axes if a in mesh.shape)
@@ -301,13 +297,13 @@ def sparse_decode_distributed_full(q, k_full, v_full, kpage_li, li, pos, *,
     bspec = ba if ba else None
     kspec = ka if ka else None
     sspec = sa if sa else None
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, kspec, None, None),
                   P(None, bspec, sspec, kspec, None),
                   P(None, bspec, sspec, kspec, None),
                   P(bspec, sspec, kspec, None), P(), P()),
-        out_specs=P(bspec, kspec, None, None), check_rep=False)(
+        out_specs=P(bspec, kspec, None, None), check_vma=False)(
             q, k_full, v_full, kpage_li, li, pos)
 
 
@@ -351,7 +347,7 @@ def attend_pages_paged(q: jax.Array, k_pool_li: jax.Array,
                        n_demand: int = 0) -> jax.Array:
     """Attend q [R,KV,G,D] to physically-gathered pages.
 
-    k_pool_li / v_pool_li [P,page,KV,D] (one layer of the pool); idx
+    k_pool_li / v_pool_li [P,KV,page,D] (one layer of the pool); idx
     [R,KV,K] logical page ids (for position masking), phys [R,KV,K]
     physical page ids (for the gather); pos [R] per-request frontier.
     Fully-masked rows (padded batch slots) produce zeros, not NaNs.
@@ -379,12 +375,12 @@ def attend_pages_paged(q: jax.Array, k_pool_li: jax.Array,
     if hot_map is not None:
         slot = hot_map[phys]                       # [R,KV,K]; -1 = demand
         phys = jnp.where(slot >= 0, n_demand + slot, phys)
-    kv = k_pool_li.shape[2]
+    kv = k_pool_li.shape[1]
     hi = jnp.arange(kv)[None, :, None]
     # advanced indices (phys [R,KV,K], head [1,KV,1]) broadcast together,
     # picking each KV head's own selected pages: [R,KV,K,page,D]
-    kg = kv_dequant_f32(k_pool_li[phys, :, hi])
-    vg = kv_dequant_f32(v_pool_li[phys, :, hi])
+    kg = kv_dequant_f32(k_pool_li[phys, hi])
+    vg = kv_dequant_f32(v_pool_li[phys, hi])
     if tp_axis is not None:
         q, idx, kg, vg = jax.lax.all_gather(
             (q, idx, kg, vg), tp_axis, axis=1, tiled=True)
@@ -434,18 +430,18 @@ def page_summary_from_pool(k_pool_li: jax.Array, phys: jax.Array,
     """Exact label-cache entries for pool pages: mean of the first
     ``n_tokens`` keys of each page ``phys``.
 
-    k_pool_li [P,page,KV,D]; phys [M]; n_tokens [M] (>=1).  Returns
+    k_pool_li [P,KV,page,D]; phys [M]; n_tokens [M] (>=1).  Returns
     [M,KV,D].  Both the chunked-prefill and the paged-decode paths
     recompute summaries through this one function so the selection
     scores cannot drift between the two (preemption-recompute relies on
     bitwise-identical replay).
     """
-    rows = kv_dequant_f32(k_pool_li[phys])          # [M,page,KV,D]
-    page = rows.shape[1]
-    tmask = (jnp.arange(page)[None, :, None, None]
+    rows = kv_dequant_f32(k_pool_li[phys])          # [M,KV,page,D]
+    page = rows.shape[2]
+    tmask = (jnp.arange(page)[None, None, :, None]
              < n_tokens[:, None, None, None])
     cnt = jnp.maximum(n_tokens, 1).astype(jnp.float32)
-    return (rows * tmask).sum(axis=1) / cnt[:, None, None]
+    return (rows * tmask).sum(axis=2) / cnt[:, None, None]
 
 
 def update_page_summary(kpage: jax.Array, k_new: jax.Array, pos: jax.Array,

@@ -388,8 +388,8 @@ def _paged_decode_fn(cfg: ArchConfig, kernel: str = "xla", tp: int = 1,
             k_new = mlayers.apply_rope(k_new, pos_arr, cfg.rope_theta)
             kq = sparse_attention.kv_quant(k_new[:, 0], kp_.dtype)
             vq = sparse_attention.kv_quant(v_new[:, 0], vp_.dtype)
-            kp_ = kp_.at[li, phys_w, off].set(kq)
-            vp_ = vp_.at[li, phys_w, off].set(vq)
+            kp_ = kp_.at[li, phys_w, :, off].set(kq)
+            vp_ = vp_.at[li, phys_w, :, off].set(vq)
             if n_demand:
                 # write-through into the NSB tail: when the frontier
                 # page has a staged copy, mirror the new KV bytes into
@@ -399,8 +399,8 @@ def _paged_decode_fn(cfg: ArchConfig, kernel: str = "xla", tp: int = 1,
                 # pool contents are unchanged either way.
                 slot_w = hot_map[phys_w]
                 wt = jnp.where(slot_w >= 0, n_demand + slot_w, phys_w)
-                kp_ = kp_.at[li, wt, off].set(kq)
-                vp_ = vp_.at[li, wt, off].set(vq)
+                kp_ = kp_.at[li, wt, :, off].set(kq)
+                vp_ = vp_.at[li, wt, :, off].set(vq)
             summ = sparse_attention.page_summary_from_pool(
                 kp_[li], phys_w, off + 1)
             sp_ = sp_.at[li, phys_w].set(summ)
@@ -505,16 +505,18 @@ def _paged_prefill_fn(cfg: ArchConfig, chunk: int, tp: int = 1,
             k_new = mlayers.apply_rope(k_new, pos[None, :], cfg.rope_theta)
             kq = sparse_attention.kv_quant(k_new[0], kp_.dtype)
             vq = sparse_attention.kv_quant(v_new[0], vp_.dtype)
-            kp_ = kp_.at[li, phys_w, off].set(kq)
-            vp_ = vp_.at[li, phys_w, off].set(vq)
+            kp_ = kp_.at[li, phys_w, :, off].set(kq)
+            vp_ = vp_.at[li, phys_w, :, off].set(vq)
             summ = sparse_attention.page_summary_from_pool(
                 kp_[li], phys_s, cnts)
             sp_ = sp_.at[li, phys_s].set(summ)
             # dense causal attention over the paged context: the block
             # table linearises this request's pages back into logical
             # order, so positions align with q_offset=start
-            kctx = kp_[li, bt].reshape(1, nl * page, kv_l, cfg.hd)
-            vctx = vp_[li, bt].reshape(1, nl * page, kv_l, cfg.hd)
+            kctx = kp_[li, bt].swapaxes(1, 2).reshape(1, nl * page, kv_l,
+                                                      cfg.hd)
+            vctx = vp_[li, bt].swapaxes(1, 2).reshape(1, nl * page, kv_l,
+                                                      cfg.hd)
             if tp_axis is not None:
                 # same bitwise mechanism as decode: the context view is
                 # gathered from the sharded pools (pool *storage* stays
@@ -544,17 +546,6 @@ def _paged_prefill_fn(cfg: ArchConfig, chunk: int, tp: int = 1,
     return fn
 
 
-def _norm_spec(spec: P) -> P:
-    """Strip trailing Nones: jitted-call cache keys compare PartitionSpecs
-    *literally* (on jax 0.4.3x ``P(a, None) != P(a)``), and shard_map
-    output shardings come back trailing-None-normalised — pools must be
-    device_put with the same normal form or the second call retraces."""
-    dims = list(spec)
-    while dims and dims[-1] is None:
-        dims.pop()
-    return P(*dims)
-
-
 def _shard_serve_fn(fn, mesh, param_specs, n_rep_args: int,
                     sel_out: bool = False, esel_out: bool = False,
                     axis: str = sharding.SERVE_TP_AXIS):
@@ -571,20 +562,18 @@ def _shard_serve_fn(fn, mesh, param_specs, n_rep_args: int,
     the host reassembles the global ``[L,R,KV,K]`` selection, so the
     allocator/NSB/capture layers keep seeing one physical page-id space.
     """
-    from jax.experimental.shard_map import shard_map
-
     kv_spec, s_spec = sharding.serve_pool_specs(axis)
     in_specs = (param_specs, kv_spec, kv_spec, s_spec) \
         + (P(),) * n_rep_args
     out_specs = (P(), kv_spec, kv_spec, s_spec)
     if sel_out:
-        out_specs = out_specs + (P(None, None, axis, None),)
+        out_specs = out_specs + (P(None, None, axis),)
     if esel_out:
         # routed expert ids: router and residual stream are replicated
         # under serve TP, so every shard computes the identical routing
         out_specs = out_specs + (P(),)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class PagedEngine:
@@ -615,7 +604,7 @@ class PagedEngine:
       (fp32 online softmax), not bitwise.
     * ``donate_pools`` — donate the k/v/s pool buffers into the decode
       and prefill jits, so XLA updates pages in place instead of copying
-      the full ``[L,P,page,KV,D]`` pools every call.
+      the full ``[L,P,KV,page,D]`` pools every call.
     * ``row_bucketing`` — pad ragged decode batches to power-of-two row
       buckets (NULL block-table rows) instead of always to
       ``max_batch``: padded compute tracks the live batch while the
@@ -867,8 +856,10 @@ class PagedEngine:
         # staged copies live there, addressed via the hot-map.  The
         # summary pool stays demand-sized — selection never reads the
         # tail, only the attention gather does.
-        shape = (cfg.n_layers, self.n_pages + self.nsb_slots, self.page,
-                 cfg.n_kv_heads, cfg.hd)
+        # [L, P, KV, page, D]: one head's page is a contiguous
+        # [page, D] slab, the tile the Pallas kernel DMAs per grid step
+        shape = (cfg.n_layers, self.n_pages + self.nsb_slots,
+                 cfg.n_kv_heads, self.page, cfg.hd)
         self.k_pool = jnp.zeros(shape, kv_dt)
         self.v_pool = jnp.zeros(shape, kv_dt)
         self.s_pool = jnp.zeros(
@@ -910,9 +901,9 @@ class PagedEngine:
             # to tp=1
             kv_spec, s_spec = sharding.serve_pool_specs()
             self._pool_shardings = (
-                NamedSharding(mesh, _norm_spec(kv_spec)),
-                NamedSharding(mesh, _norm_spec(kv_spec)),
-                NamedSharding(mesh, _norm_spec(s_spec)))
+                NamedSharding(mesh, kv_spec),
+                NamedSharding(mesh, kv_spec),
+                NamedSharding(mesh, s_spec))
             self.k_pool = jax.device_put(self.k_pool,
                                          self._pool_shardings[0])
             self.v_pool = jax.device_put(self.v_pool,
@@ -1284,7 +1275,7 @@ class PagedEngine:
                 req.first_token_at = self.now
                 req.last_token_at = self.now
                 req.token_ticks.append(self.now)
-                req.last_logits = lg
+                req.first_logits = req.last_logits = lg
                 self.stats.tokens_out += 1
                 if req.resumed_at >= 0:
                     # preempted before its first token: the resume gap

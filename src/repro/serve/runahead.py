@@ -12,7 +12,7 @@ Three pieces, mirroring the paper's decomposition:
 
 :class:`NSBHotTier` — the physical staging buffer.  The engine extends
 its K/V pools with ``n_slots`` extra *tail* pages (``[L, n_demand +
-n_slots, page, KV, D]``); this class owns the mapping from demand
+n_slots, KV, page, D]``); this class owns the mapping from demand
 physical page id -> staged tail slot (the *hot-map*), FIFO slot
 recycling, and explicit invalidation.  Staged pages are byte copies made
 by a jitted gather; the demand region and the block tables stay

@@ -119,6 +119,7 @@ class Request:
     finished_at: float = -1.0
     n_preemptions: int = 0
     cached_tokens: int = 0          # prompt tokens skipped, last admission
+    first_logits: np.ndarray | None = None   # prefill's, first token
     last_logits: np.ndarray | None = None
     spilled: bool = False           # snapshot lives in the host spill tier
     resumed_at: float = -1.0        # last re-admission after a preemption
@@ -603,13 +604,14 @@ class Scheduler:
         must go through :meth:`commit` before anything dispatches it.
         """
         # share the immutable per-request arrays: prompts are never
-        # mutated and last_logits only rebound, so the shadow can alias
-        # them instead of copying megabytes per draft
+        # mutated and logits only rebound, so the shadow can alias them
+        # instead of copying megabytes per draft
         memo: dict = {}
         for req in list(self.running) + list(self.waiting):
             memo[id(req.prompt)] = req.prompt
-            if req.last_logits is not None:
-                memo[id(req.last_logits)] = req.last_logits
+            for lg in (req.first_logits, req.last_logits):
+                if lg is not None:
+                    memo[id(lg)] = lg
         # the idle-evict hook is an engine-bound callback: detach it
         # around the copy so (a) deepcopy never recurses into the
         # engine, and (b) the shadow cannot release real session holds

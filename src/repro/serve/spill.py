@@ -70,7 +70,7 @@ class HostSpillPool:
 
     Layout per slot (one physical page, all layers):
 
-    * ``k``/``v``: ``[L, page, KV, D]`` in the pool dtype, or int8 with
+    * ``k``/``v``: ``[L, KV, page, D]`` in the pool dtype, or int8 with
       per-(slot, layer) scales when ``compress=True``;
     * ``s``: ``[L, KV, D]`` fp32 page summaries, always exact.
 
@@ -87,7 +87,7 @@ class HostSpillPool:
         self.n_slots = n_slots
         self.dtype = np.dtype(dtype)
         self.compress = bool(compress)
-        shape = (n_slots, n_layers, page_tokens, n_kv_heads, head_dim)
+        shape = (n_slots, n_layers, n_kv_heads, page_tokens, head_dim)
         store_dt = np.int8 if self.compress else self.dtype
         self._k = np.zeros(shape, store_dt)
         self._v = np.zeros(shape, store_dt)
@@ -136,7 +136,7 @@ class HostSpillPool:
     def store(self, slots, k, v, s) -> None:
         """Write page snapshots into ``slots``.
 
-        ``k``/``v`` are ``[n, L, page, KV, D]`` device-read bytes in the
+        ``k``/``v`` are ``[n, L, KV, page, D]`` device-read bytes in the
         pool dtype, ``s`` is ``[n, L, KV, D]`` fp32; all are pinned to
         host before landing so the pool never holds device buffers."""
         slots = np.asarray(list(slots), dtype=np.int64)

@@ -2,7 +2,7 @@
 
 Models annotate activations with ``constrain(x, "batch", None, "mlp")``;
 outside a mesh context this is a no-op (CPU unit tests), inside
-``jax.sharding.use_mesh`` it becomes ``with_sharding_constraint``.
+``jax.set_mesh`` it becomes ``with_sharding_constraint``.
 
 Default rules implement DP("pod","data") x TP("model") with FSDP on the
 "data" axis for large parameters and EP on "model" for divisible expert
@@ -50,23 +50,14 @@ def get_rules() -> dict:
     return dict(_rules)
 
 
-def _get_abstract_mesh():
-    # jax >= 0.5 exposes this at jax.sharding; 0.4.3x only at jax._src.mesh
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        from jax._src import mesh as _mesh_mod
-        fn = getattr(_mesh_mod, "get_abstract_mesh", None)
-    return fn() if fn is not None else None
-
-
 def _mesh_axes() -> dict:
-    try:
-        m = _get_abstract_mesh()
-    except Exception:
-        return {}
-    # unset contexts read back as None (>=0.5) or an empty tuple (0.4.3x)
-    shape = getattr(m, "shape", None)
-    return dict(shape) if shape else {}
+    """Auto axes of the current mesh, name -> size.  Manual axes (the
+    body of a ``shard_map``) are left out: a sharding constraint there
+    may name none of them, so ``constrain`` is a no-op inside one."""
+    m = jax.sharding.get_abstract_mesh()
+    return {name: size for (name, size), kind in zip(m.shape.items(),
+                                                     m.axis_types)
+            if kind != jax.sharding.AxisType.Manual}
 
 
 def resolve(*logical, mesh_axes: dict | None = None) -> P:
@@ -206,11 +197,13 @@ _SERVE_TP_SHARDED = re.compile(r"\b(wq|wk|wv|bq|bk|bv)$")
 
 def serve_pool_specs(axis: str = SERVE_TP_AXIS) -> tuple[P, P]:
     """(k/v pool spec, summary pool spec) for the paged engine's physical
-    pools: ``[L, P, page, KV, D]`` and ``[L, P, KV, D]``, sharded on the
+    pools: ``[L, P, KV, page, D]`` and ``[L, P, KV, D]``, sharded on the
     KV-head dim only — the page-id dim is never sharded, so the
     allocator/scheduler/NVR-capture layers keep one global physical
-    page-id space."""
-    return (P(None, None, None, axis, None), P(None, None, axis, None))
+    page-id space.  Written without trailing ``None``s, the form the
+    outputs of ``shard_map`` carry, so a pool placed with these specs
+    and one returned by a step hit the same jit cache entry."""
+    return P(None, None, axis), P(None, None, axis)
 
 
 def serve_param_specs(params, axis: str = SERVE_TP_AXIS):

@@ -8,11 +8,6 @@ import pytest
 
 from repro.train import checkpoint, fault
 
-_requires_explicit_sharding = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="needs the jax>=0.5 explicit-sharding API (AxisType/set_mesh); "
-           "gated on older jax")
-
 
 @pytest.fixture
 def state():
@@ -52,7 +47,6 @@ def test_restore_missing_array_fails(tmp_path, state):
         checkpoint.restore(d, state)
 
 
-@_requires_explicit_sharding
 def test_restore_with_shardings_replaces_devices(tmp_path, state):
     """Elastic restore: same checkpoint re-placed under a (new) mesh."""
     d = str(tmp_path)

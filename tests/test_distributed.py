@@ -8,11 +8,6 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_requires_explicit_sharding = pytest.mark.skipif(
-    not hasattr(__import__("jax").sharding, "AxisType"),
-    reason="needs the jax>=0.5 explicit-sharding API (AxisType/set_mesh); "
-           "gated on older jax")
-
 
 def run_py(code: str, n_dev: int = 8, timeout: int = 300):
     env = dict(os.environ)
@@ -23,7 +18,6 @@ def run_py(code: str, n_dev: int = 8, timeout: int = 300):
 
 
 @pytest.mark.slow
-@_requires_explicit_sharding
 def test_distributed_sparse_decode_exact():
     r = run_py(
         "import runpy, sys; sys.argv=['x'];"
@@ -34,7 +28,6 @@ def test_distributed_sparse_decode_exact():
 
 
 @pytest.mark.slow
-@_requires_explicit_sharding
 def test_sharded_train_step_on_host_mesh():
     code = """
 import jax, numpy as np
@@ -62,7 +55,6 @@ print("SHARDED_OK", losses[0], losses[-1])
 
 
 @pytest.mark.slow
-@_requires_explicit_sharding
 def test_compressed_psum_matches_exact():
     code = """
 import jax, jax.numpy as jnp, numpy as np

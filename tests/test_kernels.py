@@ -216,7 +216,7 @@ def test_topk_pages_selects_highest():
     (1, 256, 2, 2, 128, True, 128, 128),
 ])
 def test_flash_prefill(b, s, h, kv, d, causal, bq, bk, dtype, rtol):
-    from repro.kernels.flash_prefill import flash_prefill
+    from repro.kernels import flash_prefill
     from repro.models.layers import chunked_attention
     q = rand((b, s, h, d), dtype)
     k = rand((b, s, kv, d), dtype)

@@ -27,10 +27,10 @@ def _pool_case(r, kv, g, d, n_pages, n_logical, k_sel, page,
     per-request frontiers, TopK selection by the real scorer."""
     q = jnp.asarray(RNG.normal(size=(r, kv, g, d)), jnp.float32)
     kp = sparse_attention.kv_quant(
-        jnp.asarray(RNG.normal(size=(n_pages, page, kv, d)), jnp.float32),
+        jnp.asarray(RNG.normal(size=(n_pages, kv, page, d)), jnp.float32),
         pool_dtype)
     vp = sparse_attention.kv_quant(
-        jnp.asarray(RNG.normal(size=(n_pages, page, kv, d)), jnp.float32),
+        jnp.asarray(RNG.normal(size=(n_pages, kv, page, d)), jnp.float32),
         pool_dtype)
     spool = jnp.asarray(RNG.normal(size=(n_pages, kv, d)), jnp.float32)
     bt = np.zeros((r, n_logical), np.int32)

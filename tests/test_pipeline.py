@@ -8,11 +8,6 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_requires_explicit_sharding = pytest.mark.skipif(
-    not hasattr(__import__("jax").sharding, "AxisType"),
-    reason="needs the jax>=0.5 explicit-sharding API (AxisType/set_mesh); "
-           "gated on older jax")
-
 CODE = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.train import pipeline_parallel as pp
@@ -68,7 +63,6 @@ print("PP_GRAD_OK  bubble=%.2f" % pp.bubble_fraction(4, M))
 
 
 @pytest.mark.slow
-@_requires_explicit_sharding
 def test_pipeline_parallel_forward_and_grads():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

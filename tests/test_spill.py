@@ -134,8 +134,8 @@ class TestSpillAllocator:
 
 class TestHostSpillPool:
     def _planes(self, rng, n, layers=2, page=4, kv=2, d=8):
-        k = rng.normal(size=(n, layers, page, kv, d)).astype(np.float32)
-        v = rng.normal(size=(n, layers, page, kv, d)).astype(np.float32)
+        k = rng.normal(size=(n, layers, kv, page, d)).astype(np.float32)
+        v = rng.normal(size=(n, layers, kv, page, d)).astype(np.float32)
         s = rng.normal(size=(n, layers, kv, d)).astype(np.float32)
         return k, v, s
 

@@ -3,8 +3,9 @@
 Sharded engines need more than one jax device, and the device count is
 fixed at jax init — so every sharded test runs in a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the same pattern
-as tests/test_distributed.py).  Only ``jax.make_mesh`` + ``shard_map`` +
-``NamedSharding`` are used, so these run on jax 0.4.3x as well.
+as tests/test_distributed.py).  The meshes come from
+``launch.mesh.make_serve_mesh`` (``Auto`` axes) and the steps run under
+``jax.shard_map``.
 
 The acceptance bar is *bitwise*: at tp=2 (and tp=4 where head counts
 divide) every request's token stream and logits must equal the tp=1
@@ -70,8 +71,9 @@ class TestServeParamSpecs:
         from repro import sharding
 
         kv_spec, s_spec = sharding.serve_pool_specs()
-        assert kv_spec == P(None, None, None, "model", None)
-        assert s_spec == P(None, None, "model", None)
+        # pools are [L, P, KV, page, D] and [L, P, KV, D]: dim 2 only
+        assert kv_spec == P(None, None, "model")
+        assert s_spec == P(None, None, "model")
 
 
 # -- sharded engines (subprocess, forced host devices) ------------------------
@@ -129,7 +131,7 @@ assert_bitwise(base, tp2)
 # pools physically sharded: each device holds half the KV-head dim
 shards = tp2.k_pool.addressable_shards
 assert len(shards) == 2
-assert [s.data.shape[3] for s in shards] == [cfg.n_kv_heads // 2] * 2
+assert [s.data.shape[2] for s in shards] == [cfg.n_kv_heads // 2] * 2
 assert tp2.s_pool.addressable_shards[0].data.shape[2] \\
     == cfg.n_kv_heads // 2
 
@@ -256,7 +258,7 @@ assert_bitwise(base, tp2)
 shards = tp2.k_pool.addressable_shards
 assert len(shards) == 2
 assert tp2.k_pool.shape[1] == tp2.n_pages + tp2.nsb_slots
-assert [s.data.shape[3] for s in shards] == [cfg.n_kv_heads // 2] * 2
+assert [s.data.shape[2] for s in shards] == [cfg.n_kv_heads // 2] * 2
 
 m = tp2.metrics()
 assert m["runahead_staged_pages"] > 0
@@ -295,7 +297,7 @@ assert_bitwise(base, tight)
 # restored pools stay physically sharded after the host round-trip
 shards = tight.k_pool.addressable_shards
 assert len(shards) == 2
-assert [s.data.shape[3] for s in shards] == [cfg.n_kv_heads // 2] * 2
+assert [s.data.shape[2] for s in shards] == [cfg.n_kv_heads // 2] * 2
 tight.allocator.check_tier_invariants()
 m = tight.metrics()
 assert m["tp"] == 2 and m["swap_out_pages"] == m["swap_in_pages"] > 0
